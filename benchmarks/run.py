@@ -89,6 +89,10 @@ def _quick_smoke() -> int:
     if proc.returncode:
         return proc.returncode
 
+    # JAX only after the child above has exited: one process owns a chip
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     from . import (kernel_bench, table1_codecs, table2_seismic, table3_graph,
                    table4_pipeline, table5_scale, table6_mutation,
                    table7_overlap)
@@ -138,6 +142,9 @@ def main() -> None:
 
     if args.quick:
         sys.exit(_quick_smoke())
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     rows = []
     by_section: dict[str, list] = {}

@@ -15,6 +15,7 @@ Run:  PYTHONPATH=src python examples/retrieval_serving.py [--n-docs 8000]
 import argparse
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -38,7 +39,8 @@ def _serve(name, retriever, Q, truth, col, k):
     codec = retriever.cfg.codec
     comp = col.fwd.storage_bytes(codec)["components"]
     print(f"  {name:8s} {codec:13s} recall@{k}={rec:.3f} "
-          f"{dt:8.0f} µs/query (CPU)  components={comp/2**20:6.2f} MiB")
+          f"{dt:8.0f} µs/query ({jax.devices()[0].device_kind})  "
+          f"components={comp/2**20:6.2f} MiB")
 
 
 def main() -> None:

@@ -1,8 +1,4 @@
 """repro — Forward Index Compression for Learned Sparse Retrieval,
 as a production-grade JAX/Pallas framework. See DESIGN.md."""
 
-from . import compat as _compat
-
-_compat.install()
-
 __version__ = "1.1.0"

@@ -437,10 +437,6 @@ def decode_doc_rows_dotvbyte(ctrl_rows: jnp.ndarray, data_rows: jnp.ndarray) -> 
     return decode_doc_rows("dotvbyte", {"ctrl_rows": ctrl_rows, "data_rows": data_rows})
 
 
-#: codecs already warned about missing fused rows kernels (warn once)
-_NO_ROWS_KERNEL_WARNED: set = set()
-
-
 def _check_rows_backend(backend: str) -> None:
     from repro.kernels.modes import SCORING_BACKENDS
 
@@ -450,17 +446,11 @@ def _check_rows_backend(backend: str) -> None:
         )
 
 
-def _warn_no_rows_kernel(codec: str) -> None:
-    if codec not in _NO_ROWS_KERNEL_WARNED:
-        import warnings
-
-        _NO_ROWS_KERNEL_WARNED.add(codec)
-        warnings.warn(
-            f"codec {codec!r} has no fused rows kernel registered; "
-            f"serving backend='pallas' through the jnp path",
-            RuntimeWarning,
-            stacklevel=3,
-        )
+def _no_rows_kernel(codec: str, backend: str) -> ValueError:
+    return ValueError(
+        f"backend={backend!r} asks for a fused rows kernel, but codec "
+        f"{codec!r} has none registered; serve it with backend='jnp'"
+    )
 
 
 def _gather_decode_rows(codec: str, arrays, docs: jnp.ndarray):
@@ -527,19 +517,19 @@ def score_candidate_rows(
     (scalar-prefetch HBM→VMEM row gather, decode and dot in VMEM —
     decoded components never touch HBM) in its default — compiled —
     mode, while ``"pallas_interpret"`` / ``"pallas_compiled"`` pin the
-    kernel ``mode`` explicitly (``repro.kernels.modes``).  Codecs with
-    no registered rows kernel fall back to jnp with a one-time warning.
-    All paths return identical scores (asserted by the parity suite
-    and ``make kernel-parity``)."""
+    kernel ``mode`` explicitly (``repro.kernels.modes``).  A kernel
+    backend on a codec with no registered rows kernel raises.  All
+    paths return identical scores (asserted by the parity suite and
+    ``make kernel-parity``)."""
     _check_rows_backend(backend)
     if backend != "jnp":
         from repro.kernels.modes import backend_mode
         from repro.kernels.registry import rows_scorer
 
         fn = rows_scorer(codec)
-        if fn is not None:
-            return fn(arrays, docs, q, scale, backend_mode(backend))
-        _warn_no_rows_kernel(codec)
+        if fn is None:
+            raise _no_rows_kernel(codec, backend)
+        return fn(arrays, docs, q, scale, backend_mode(backend))
     comps, vals, nnz = _gather_decode_rows(codec, arrays, docs)
     return score_doc_rows(q, comps, vals, nnz, scale)
 
@@ -561,7 +551,7 @@ def score_candidate_rows_batch(
     every resident query. ``backend="pallas"`` dispatches to the codec's
     ``rows_scores_batch`` kernel registry entry, which keeps each
     decoded row in VMEM across the whole query batch; the jnp path
-    hoists the decode out of a ``vmap`` over ``score_doc_rows``, so
+    hoists the decode out of a ``lax.map`` over ``score_doc_rows``, so
     per-query scores are bitwise those of the single-query path."""
     _check_rows_backend(backend)
     if backend != "jnp":
@@ -569,13 +559,14 @@ def score_candidate_rows_batch(
         from repro.kernels.registry import rows_batch_scorer
 
         fn = rows_batch_scorer(codec)
-        if fn is not None:
-            return fn(arrays, docs, Q, scale, backend_mode(backend))
-        _warn_no_rows_kernel(codec)
+        if fn is None:
+            raise _no_rows_kernel(codec, backend)
+        return fn(arrays, docs, Q, scale, backend_mode(backend))
     comps, vals, nnz = _gather_decode_rows(codec, arrays, docs)
-    # comps/vals/nnz carry no query axis → the decode stays un-batched
-    # under vmap (computed once); only the q-gather + FMA replicate
-    return jax.vmap(lambda q: score_doc_rows(q, comps, vals, nnz, scale))(Q)
+    # decoded once; the q-gather + FMA then run one query at a time: a
+    # query axis on the [C, L] gather would sit in the minor dimension
+    # of a TPU layout, padded to 128 lanes (26 GB at 200k rows)
+    return jax.lax.map(lambda q: score_doc_rows(q, comps, vals, nnz, scale), Q)
 
 
 def score_doc_rows(

@@ -55,7 +55,6 @@ __all__ = [
     "PQ_K",
     "PQ_M",
     "code_factor",
-    "n_vq_streams",
     "check_vq",
     "encode_rows_values",
     "encode_block_values",
@@ -65,7 +64,6 @@ __all__ = [
     "dequant_pq",
     "decode_codes",
     "infer_rows_vq",
-    "rows_vq_streams",
     "value_payload_bytes",
 ]
 
@@ -105,15 +103,6 @@ def code_factor(vq: str) -> int:
     if vq == "pq":
         return PQ_M
     return 1
-
-
-def n_vq_streams(vq: str) -> int:
-    """How many extra payload streams the rows kernel threads for vq
-    (lo+scale columns for scalar quant, the resident codebook for PQ)."""
-    check_vq(vq)
-    if vq in _SQ_KEYS:
-        return 2
-    return 1 if vq == "pq" else 0
 
 
 # ---------------------------------------------------------------------------
@@ -324,7 +313,7 @@ def decode_codes(vq: str, codes, lo=None, step=None, codebook_flat=None):
 
 
 # ---------------------------------------------------------------------------
-# rows-array plumbing (vq inference + kernel stream marshalling)
+# rows-array plumbing (vq inference)
 # ---------------------------------------------------------------------------
 
 #: every payload key a value codec can add to a rows dict
@@ -343,21 +332,6 @@ def infer_rows_vq(arrays: Mapping) -> str:
     if "vq_lo_rows" in arrays:
         return "u8_sq"
     return "f16"
-
-
-def rows_vq_streams(vq: str, arrays: Mapping) -> list:
-    """The ordered extra operand streams the rows kernel threads for
-    ``vq``: the per-row lo/scale columns (gathered per grid step like
-    any row stream) or the grid-resident flat codebook ``[1, K·M]``."""
-    import jax.numpy as jnp
-
-    if vq in _SQ_KEYS:
-        lo_key, sc_key = _SQ_KEYS[vq]
-        return [jnp.asarray(arrays[lo_key]), jnp.asarray(arrays[sc_key])]
-    if vq == "pq":
-        cb = jnp.asarray(arrays["vq_codebook"], jnp.float32)
-        return [cb.reshape(1, PQ_K * PQ_M)]
-    return []
 
 
 def value_payload_bytes(arrays: Mapping) -> int:

@@ -30,7 +30,7 @@ import dataclasses
 
 import numpy as np
 
-from repro.core.forward_index import ForwardIndex
+from repro.core.forward_index import VALUE_FORMATS, ForwardIndex
 
 __all__ = [
     "SyntheticConfig",
@@ -38,6 +38,7 @@ __all__ = [
     "lilsr_config",
     "SparseCollection",
     "generate_collection",
+    "generate_collection_device",
     "densify",
 ]
 
@@ -136,12 +137,7 @@ def generate_collection(
     # scrambled labels: identity order must carry no locality
     relabel = rng.permutation(cfg.dim).astype(np.uint32)
 
-    def mixture_logits(n_rows: int, doc_topics: np.ndarray) -> np.ndarray:
-        lg = np.tile(background, (n_rows, 1))
-        for r in range(n_rows):
-            for t in doc_topics[r]:
-                lg[r, topic_comps[t]] += cfg.topic_concentration
-        return lg
+    mixture_logits = _mixture_logits_fn(cfg, background, topic_comps)
 
     docs: list[tuple[np.ndarray, np.ndarray]] = []
     doc_topic_sets = rng.integers(0, cfg.n_topics, size=(cfg.n_docs, 3))
@@ -158,7 +154,24 @@ def generate_collection(
             ) + np.float32(0.05)
             docs.append((np.sort(relabel[comps]), vals))
 
-    # queries share topics with a focus document
+    fwd = ForwardIndex.from_docs(docs, cfg.dim, value_format=value_format)
+    q_comps, q_vals = _queries(cfg, rng, mixture_logits, doc_topic_sets, relabel)
+    return SparseCollection(config=cfg, fwd=fwd, query_comps=q_comps, query_vals=q_vals)
+
+
+def _mixture_logits_fn(cfg: SyntheticConfig, background, topic_comps):
+    def mixture_logits(n_rows: int, doc_topics: np.ndarray) -> np.ndarray:
+        lg = np.tile(background, (n_rows, 1))
+        for r in range(n_rows):
+            for t in doc_topics[r]:
+                lg[r, topic_comps[t]] += cfg.topic_concentration
+        return lg
+
+    return mixture_logits
+
+
+def _queries(cfg, rng, mixture_logits, doc_topic_sets, relabel):
+    """Queries share topics with a focus document."""
     q_comps, q_vals = [], []
     focus = rng.integers(0, cfg.n_docs, size=cfg.n_queries)
     qnnz = np.clip(rng.poisson(cfg.query_nnz_mean, size=cfg.n_queries), 2, cfg.dim // 8)
@@ -170,6 +183,59 @@ def generate_collection(
         ) + np.float32(0.05)
         q_comps.append(np.sort(relabel[comps]))
         q_vals.append(vals)
+    return q_comps, q_vals
 
-    fwd = ForwardIndex.from_docs(docs, cfg.dim, value_format=value_format)
+
+def generate_collection_device(
+    cfg: SyntheticConfig, value_format: str = "f32", batch: int = 2048
+) -> SparseCollection:
+    """:func:`generate_collection`'s model, with the documents drawn in
+    bulk on the default JAX device: the same Zipf background, topic
+    boosts, Gumbel top-k sampling without replacement, relabelling and
+    gamma activations, one ``[batch, dim]`` draw per step instead of a
+    host loop — the generator for corpora of hundreds of thousands of
+    documents. Seeded by ``cfg.seed``; its draws differ from the host
+    generator's, so the two give different collections of the same
+    statistics."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(cfg.seed)
+    background, topic_comps = _topic_logits(cfg, rng)
+    relabel = rng.permutation(cfg.dim).astype(np.uint32)
+    doc_topic_sets = rng.integers(0, cfg.n_topics, size=(cfg.n_docs, 3))
+    nnz = np.clip(rng.poisson(cfg.doc_nnz_mean, size=cfg.n_docs), 4, cfg.dim // 4)
+    k_max = int(nnz.max(initial=4))
+    bg, tc = jnp.asarray(background), jnp.asarray(topic_comps)
+
+    @jax.jit
+    def draw(key, topics):  # topics i32 [batch, 3] → top-k ids [batch, k_max]
+        rows = jnp.arange(topics.shape[0])[:, None]
+        boost = jnp.zeros((topics.shape[0], cfg.dim), jnp.float32).at[
+            rows, tc[topics].reshape(topics.shape[0], -1)
+        ].add(cfg.topic_concentration)
+        keys = bg + boost + jax.random.gumbel(key, boost.shape, jnp.float32)
+        return jax.lax.top_k(keys, k_max)[1]
+
+    key = jax.random.key(cfg.seed)
+    comps = []
+    for lo in range(0, cfg.n_docs, batch):
+        topics = np.zeros((batch, 3), np.int64)
+        topics[: min(batch, cfg.n_docs - lo)] = doc_topic_sets[lo : lo + batch]
+        idx = np.asarray(draw(jax.random.fold_in(key, lo), jnp.asarray(topics)))
+        idx = idx[: min(batch, cfg.n_docs - lo)]
+        live = np.arange(k_max)[None, :] < nnz[lo : lo + batch, None]
+        ids = np.sort(np.where(live, relabel[idx], cfg.dim), axis=1)
+        comps.append(ids[live])  # padding (= dim) sorted past each row's nnz
+    vals = rng.gamma(cfg.value_shape, cfg.value_scale, size=int(nnz.sum()))
+    vf = VALUE_FORMATS[value_format]
+    fwd = ForwardIndex(
+        components=np.concatenate(comps).astype(np.uint32),
+        values=vf.quantise(vals.astype(np.float32) + np.float32(0.05)),
+        offsets=np.concatenate([[0], np.cumsum(nnz)]).astype(np.int64),
+        dim=cfg.dim,
+        value_format=vf,
+    )
+    mixture_logits = _mixture_logits_fn(cfg, background, topic_comps)
+    q_comps, q_vals = _queries(cfg, rng, mixture_logits, doc_topic_sets, relabel)
     return SparseCollection(config=cfg, fwd=fwd, query_comps=q_comps, query_vals=q_vals)

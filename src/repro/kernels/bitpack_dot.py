@@ -40,23 +40,6 @@ __all__ = [
 ]
 
 
-def _decode_fixed(words: jnp.ndarray, width: jnp.ndarray, T: int) -> jnp.ndarray:
-    """Unpack T values of ``width`` bits from u32 words (LSB-first).
-    1-D form used by the rows-rescoring kernel (``rows_dot``); the tiled
-    block kernels use the [R, W] matrix decoder from ``scoring``.
-    ``words`` must carry ≥ 1 spare word for the straddle read."""
-    w32 = words.astype(jnp.uint32)
-    wu = width.astype(jnp.uint32)
-    bitpos = jax.lax.iota(jnp.uint32, T) * wu
-    wi = (bitpos >> 5).astype(jnp.int32)
-    off = bitpos & 31
-    lo = jnp.take(w32, wi, axis=0) >> off
-    hi_raw = jnp.take(w32, wi + 1, axis=0)
-    hi = jnp.where(off > 0, hi_raw << (jnp.uint32(32) - off), jnp.uint32(0))
-    mask = (jnp.uint32(1) << wu) - jnp.uint32(1)
-    return ((lo | hi) & mask).astype(jnp.int32)
-
-
 def tile_gaps(words: jnp.ndarray, widths: jnp.ndarray, T: int) -> jnp.ndarray:
     """[R, W] words + [R] widths → gaps i32 [R, T]."""
     return decode_gaps_bitpack(words, widths, T)

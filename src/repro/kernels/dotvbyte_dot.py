@@ -44,16 +44,6 @@ __all__ = [
 ]
 
 
-def decode_vec(ctrl: jnp.ndarray, data: jnp.ndarray, T: int) -> jnp.ndarray:
-    """One row's (ctrl [≥T/8] u8, data [DP] u8) → gaps i32 [T]: control
-    bits → byte offsets (exclusive prefix sum = the "scroll" amounts) →
-    dual byte gather.  Used by the rows-rescoring kernel (``rows_dot``);
-    the tiled block kernels use the [R, T] matrix decoder from
-    ``scoring``."""
-    gaps = decode_gaps_dotvbyte(ctrl[None, : T // 8], data[None, :])
-    return gaps[0]
-
-
 def tile_gaps(ctrl: jnp.ndarray, data: jnp.ndarray, T: int) -> jnp.ndarray:
     """[R, ≥T/8] ctrl + [R, DP] data → gaps i32 [R, T] (lane padding
     sliced tight before the decode)."""

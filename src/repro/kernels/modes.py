@@ -8,15 +8,14 @@ and ``scoring.score_candidate_rows{,_batch}`` — takes one ``mode`` axis:
 * ``"pallas_interpret"`` — the Pallas kernels under ``interpret=True``:
   the Python-level emulator that validates kernel *semantics* (DMA
   ordering included) on any host, at emulator speed;
-* ``"pallas_compiled"`` — the compiled tile program.  On a Mosaic-
-  capable backend (TPU) this is the real ``pallas_call`` lowering —
-  double-buffered HBM→VMEM DMA block scan, queries×tiles batched grids.
-  On hosts without Mosaic (this container is CPU-only XLA) the SAME
-  tile program is lowered through XLA instead — a jit'd ``lax.scan``
-  over the identical lane-aligned tiles, so the working set stays
-  cache-resident exactly where the TPU pipeline keeps it VMEM-resident
-  — with a one-time warning.  Either way the caller gets genuinely
-  compiled machine code, never the interpreter.
+* ``"pallas_compiled"`` — the compiled program.  On a TPU this is the
+  real ``pallas_call`` lowering through Mosaic, always: the XLA
+  lowering below is unreachable there (``chip_smoke.py`` checks
+  ``resolve_lowering(None) == "mosaic"`` before any work).  On hosts
+  without Mosaic (the CPU test runs) the SAME chain is lowered through
+  XLA instead — a jit'd graph over the identical lane-aligned tiles —
+  with a one-time warning, so the tests compile it rather than
+  interpret it.
 
 ``mode=None`` (and the back-compat booleans: ``interpret=True`` ↦
 ``pallas_interpret``, ``interpret=False`` ↦ ``pallas_compiled``) resolve

@@ -21,9 +21,9 @@ them fused. Each entry is a ``KernelSet``:
 
 Registering a ``KernelSet`` under a layout codec's name makes EVERY
 engine serve that codec fused with zero engine edits — the exact
-contract the layout registry established for the jnp path. Codecs
-without an entry (or without the relevant field) fall back to jnp with
-a one-time warning (``scoring.score_candidate_rows``).
+contract the layout registry established for the jnp path. Asking a
+codec without an entry (or without the relevant field) for a kernel
+backend raises (``scoring.score_candidate_rows``).
 
 Every entry's last parameter is the kernel execution ``mode``
 (``repro.kernels.modes``): a mode string, ``None`` (auto → compiled),
@@ -106,8 +106,8 @@ def available_kernels() -> list[str]:
 
 def rows_scorer(codec: str) -> Optional[Callable]:
     """The fused rows-rescoring entry for ``codec``, or None when the
-    codec has no registered rows kernel (callers then fall back to
-    jnp — see ``scoring.score_candidate_rows``)."""
+    codec has no registered rows kernel (``scoring.score_candidate_
+    rows`` then raises)."""
     factory = _KERNELS.get(codec)
     if factory is None:
         return None
@@ -117,8 +117,7 @@ def rows_scorer(codec: str) -> Optional[Callable]:
 def rows_batch_scorer(codec: str) -> Optional[Callable]:
     """The fused decode-once/score-many rows entry for ``codec`` —
     one shared candidate set, a resident query batch — or None when
-    unregistered (callers fall back to the jnp batch path — see
-    ``scoring.score_candidate_rows_batch``)."""
+    unregistered (``scoring.score_candidate_rows_batch`` then raises)."""
     factory = _KERNELS.get(codec)
     if factory is None:
         return None
@@ -163,10 +162,7 @@ def _make_rows(codec: str):
             codec,
             qp,
             docs,
-            arrays["vals_rows"],
-            arrays["nnz_rows"],
-            *value_codecs.rows_vq_streams(vq, arrays),
-            *rows_dot._payload_streams(codec, arrays),
+            _rows_arrays(arrays),
             scale=float(scale),
             vq=vq,
             interpret=low == "interpret",
@@ -199,10 +195,7 @@ def _make_rows_batch(codec: str):
             codec,
             Qp,
             docs,
-            arrays["vals_rows"],
-            arrays["nnz_rows"],
-            *value_codecs.rows_vq_streams(vq, arrays),
-            *rows_dot._payload_streams(codec, arrays),
+            _rows_arrays(arrays),
             scale=float(scale),
             vq=vq,
             interpret=low == "interpret",

@@ -45,13 +45,6 @@ __all__ = [
 ]
 
 
-def decode_vec(ctrl: jnp.ndarray, data: jnp.ndarray, T: int) -> jnp.ndarray:
-    """One row's (ctrl [≥T/4] u8, data [DP] u8) → gaps i32 [T]; used by
-    the rows-rescoring kernel (``rows_dot``)."""
-    gaps = decode_gaps_streamvbyte(ctrl[None, : T // 4], data[None, :])
-    return gaps[0]
-
-
 def tile_gaps(ctrl: jnp.ndarray, data: jnp.ndarray, T: int) -> jnp.ndarray:
     """[R, ≥T/4] ctrl + [R, DP] data → gaps i32 [R, T] (lane padding
     sliced tight before the decode)."""

@@ -187,7 +187,7 @@ def dma_block_scan(
     return pl.pallas_call(
         kernel,
         in_specs=[pl.BlockSpec((1, V), lambda: (0, 0))]
-        + [pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)] * n_s,
+        + [pl.BlockSpec(memory_space=pl.ANY)] * n_s,
         out_specs=pl.BlockSpec((Bp, out_dim), lambda: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, out_dim), jnp.float32),
         interpret=interpret,

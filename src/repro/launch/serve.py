@@ -51,12 +51,20 @@ import time
 import numpy as np
 
 
+def _device_label() -> str:
+    """The device the timings below ran on, as JAX reports it."""
+    import jax
+
+    d = jax.devices()[0]
+    return f"{d.platform}:{d.device_kind} x{jax.device_count()}"
+
+
 def _report(name, codec, k, recs, dt_us, col, extra=""):
     comp_bytes = col.fwd.storage_bytes(codec)["components"]
     raw_bytes = col.fwd.storage_bytes("uncompressed")["components"]
     print(
         f"{name:8s} codec={codec:13s} recall@{k}={np.mean(recs):.3f} "
-        f"latency={dt_us:7.0f}µs/q (CPU) "
+        f"latency={dt_us:7.0f}µs/q ({_device_label()}) "
         f"components={comp_bytes/2**20:.1f}MiB ({8*comp_bytes/col.fwd.total_nnz:.1f} "
         f"bits/comp vs 16.0 raw, {100*(1-comp_bytes/raw_bytes):.0f}% saved){extra}"
     )
@@ -223,6 +231,7 @@ def _mutate_loadgen(col, name, codec, args, rng) -> None:
 
 def main() -> None:
     from repro.core.layout import available_layouts
+    from repro.launch import compile_cache
     from repro.serve.api import available_engines
 
     engines_known = available_engines()
@@ -296,6 +305,7 @@ def main() -> None:
     ap.add_argument("--iters", type=int, default=64, help="HNSW nodes expanded per query")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
     if args.save_index and args.load_index:
         ap.error("--save-index and --load-index are mutually exclusive")
     if args.pipeline and (args.save_index or args.load_index):

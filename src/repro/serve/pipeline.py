@@ -221,6 +221,11 @@ class SearchPlan:
             self._compiled = compiled
             return True
 
+    @property
+    def executable(self):
+        """The AOT-compiled executable ``warm`` built (None before)."""
+        return self._compiled
+
     def __call__(self, Q) -> Tuple[jnp.ndarray, jnp.ndarray]:
         Q = jnp.asarray(Q)
         n, bucket = Q.shape[0], self.key.bucket

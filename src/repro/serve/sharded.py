@@ -495,6 +495,12 @@ class ShardedRetriever:
             return None
         self._staged = None
         r = st[1].result()
+        # the double buffer at its fullest, whatever the thread timing:
+        # the staged build has landed, the previous shard is resident
+        self.peak_resident_bytes = max(
+            self.peak_resident_bytes,
+            self.resident_bytes() + sum(int(a.nbytes) for a in r.arrays.values()),
+        )
         if r.cfg.k != self._shard_k[s]:
             self._evicted_compiles += r.plans.compiles
             return None
@@ -633,6 +639,9 @@ class ShardedRetriever:
             return None
         if self._mesh_state is not None:
             return self._mesh_state
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
         from repro.dist.sharding import index_mesh, tombstone_budget
 
         mesh = index_mesh(self.cfg.n_shards)
@@ -644,13 +653,16 @@ class ShardedRetriever:
                 )
             return None
         n_local = max(sh.n_docs for sh in self.shards)
+        # shard s lives on the mesh's device s (placed once, not
+        # re-sent from one device on every call)
+        on_shards = NamedSharding(mesh, P("model"))
         if self._mesh_static is None:
             # zero-padding to common shapes is safe: padding rows are
             # unreachable (in-shard ids never exceed the shard's own
             # sentinel) and zero rows score 0 → idmap sends them to the
             # out-of-corpus sentinel, which the merge masks
             self._mesh_static = {
-                k: jnp.asarray(v)
+                k: jax.device_put(v, on_shards)
                 for k, v in layout.pad_stack(
                     [dict(sh.arrays) for sh in self.shards]
                 ).items()
@@ -676,8 +688,22 @@ class ShardedRetriever:
                 self.cfg.k, n_local, int(self._tombstones.size)
             ),
         )
-        self._mesh_state = (fn, stacked, jnp.asarray(idmaps))
+        self._mesh_state = (fn, stacked, jax.device_put(idmaps, on_shards))
         return self._mesh_state
+
+    def shard_devices(self) -> Dict[str, list]:
+        """Mesh path: for every stacked shard array (and the id map),
+        the id of the device holding each shard's slice, in shard
+        order. Empty off the mesh path."""
+        state = self._mesh()
+        if not state:
+            return {}
+        _, arrays, idmaps = state
+        out = {}
+        for name, a in {**arrays, "idmap": idmaps}.items():
+            by_shard = sorted(a.addressable_shards, key=lambda s: s.index[0].start or 0)
+            out[name] = [s.device.id for s in by_shard]
+        return out
 
     # -- serving (the Retriever surface) --------------------------------
     def make_plans(self, buckets) -> ShardedPlanCache:

@@ -8,6 +8,7 @@ import pytest
 from repro.data.synthetic import (
     SyntheticConfig,
     generate_collection,
+    generate_collection_device,
     lilsr_config,
     splade_config,
 )
@@ -40,6 +41,39 @@ def test_queries_retrieve_related_docs():
     col = generate_collection(
         SyntheticConfig(name="t", dim=2048, n_docs=500, n_queries=10,
                         doc_nnz_mean=60, query_nnz_mean=20, seed=2)
+    )
+    scores = np.stack([col.fwd.exact_scores(col.query_dense(i)) for i in range(10)])
+    top = scores.max(axis=1)
+    med = np.median(scores, axis=1)
+    assert (top > 4 * np.maximum(med, 1e-3)).mean() >= 0.8
+
+
+def test_device_generator_statistics_match_paper():
+    """The bulk device generator draws the same model: SPLADE nonzeros,
+    sorted unique in-vocabulary ids, and one collection per seed."""
+    cfg = splade_config(n_docs=300, n_queries=40, seed=3)
+    col = generate_collection_device(cfg, "f16", batch=128)
+    fwd = col.fwd
+    assert fwd.n_docs == 300 and fwd.dim == 30522
+    assert abs(fwd.total_nnz / fwd.n_docs - 119) < 12
+    assert abs(np.mean([len(c) for c in col.query_comps]) - 43) < 8
+    assert int(fwd.components.max()) < fwd.dim
+    for d in range(fwd.n_docs):
+        c = fwd.components[fwd.offsets[d] : fwd.offsets[d + 1]].astype(np.int64)
+        assert len(c) >= 4 and (np.diff(c) > 0).all()
+    assert (fwd.values > 0).all()
+    again = generate_collection_device(cfg, "f16", batch=128).fwd
+    np.testing.assert_array_equal(again.components, fwd.components)
+    np.testing.assert_array_equal(again.values, fwd.values)
+
+
+def test_device_generator_queries_retrieve_related_docs():
+    """Topic structure survives the device draw: a query's exact top
+    score stands well above the median."""
+    col = generate_collection_device(
+        SyntheticConfig(name="t", dim=2048, n_docs=500, n_queries=10,
+                        doc_nnz_mean=60, query_nnz_mean=20, seed=2),
+        batch=256,
     )
     scores = np.stack([col.fwd.exact_scores(col.query_dense(i)) for i in range(10)])
     top = scores.max(axis=1)

@@ -234,13 +234,13 @@ def test_rows_kernel_batch_matches_vmapped_single(codec):
 
 def test_kernel_registry_surface():
     """Registry mirrors the layout registry: every layout codec is
-    fused, unknown names raise listing the known ones, and an
-    unregistered codec falls back to jnp with ONE warning."""
+    fused, unknown names raise listing the known ones, and a kernel
+    backend on a codec with no rows kernel raises (single and batch
+    forms) instead of serving through jnp."""
     assert set(available_kernels()) == set(layout.available_layouts())
     with pytest.raises(ValueError, match=r"bitpack.*streamvbyte"):
         get_kernels("zstd")
-    # fallback: pallas backend on a codec with no rows kernel
-    from repro.core import scoring
+    from repro.core.scoring import score_candidate_rows_batch
     from repro.kernels import registry
 
     rng = np.random.default_rng(3)
@@ -249,28 +249,26 @@ def test_kernel_registry_surface():
     q = jnp.asarray(_query(rng, fwd.dim))
     scale = float(fwd.value_format.scale)
     saved_kernels = registry._KERNELS.pop("dotvbyte")
-    saved_warned = set(scoring._NO_ROWS_KERNEL_WARNED)
-    scoring._NO_ROWS_KERNEL_WARNED.clear()
     try:
-        with pytest.warns(RuntimeWarning, match="no fused rows kernel"):
-            got = score_candidate_rows(
-                "dotvbyte", arrays, jnp.asarray(cand), q, scale, backend="pallas"
-            )
-        import warnings as _w
-
-        with _w.catch_warnings():  # second call: warning already issued
-            _w.simplefilter("error", RuntimeWarning)
+        with pytest.raises(ValueError, match="has none registered"):
             score_candidate_rows(
                 "dotvbyte", arrays, jnp.asarray(cand), q, scale, backend="pallas"
             )
+        with pytest.raises(ValueError, match="has none registered"):
+            score_candidate_rows_batch(
+                "dotvbyte", arrays, jnp.asarray(cand), q[None], scale,
+                backend="pallas_interpret",
+            )
+        # the jnp backend needs no kernel
+        got = score_candidate_rows(
+            "dotvbyte", arrays, jnp.asarray(cand), q, scale, backend="jnp"
+        )
     finally:
         registry._KERNELS["dotvbyte"] = saved_kernels
-        scoring._NO_ROWS_KERNEL_WARNED.clear()
-        scoring._NO_ROWS_KERNEL_WARNED.update(saved_warned)
-    want = score_candidate_rows(
-        "dotvbyte", arrays, jnp.asarray(cand), q, scale, backend="jnp"
+    want = get_kernels("dotvbyte").rows_scores(
+        arrays, jnp.asarray(cand), q, scale, "pallas_interpret"
     )
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
     with pytest.raises(ValueError, match="unknown scoring backend"):
         score_candidate_rows("dotvbyte", arrays, jnp.asarray(cand), q, scale,
                              backend="mosaic")
