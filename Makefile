@@ -7,7 +7,7 @@ export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 ROUNDTRIP_DIR ?= /tmp/repro-serve-roundtrip
 ROUNDTRIP_ARGS = --engine all --compare-codecs --n-docs 400 --n-queries 8 --seed 0
 
-.PHONY: test check bench bench-fast docs-check serve-roundtrip kernel-parity shard-parity mutation-parity overlap-parity value-parity perf-gate pipeline-smoke clean
+.PHONY: test check bench bench-fast docs-check serve-roundtrip kernel-parity shard-parity mutation-parity overlap-parity value-parity pipeline-smoke clean
 
 test:            ## tier-1 suite (the CI gate)
 	$(PY) -m pytest -x -q
@@ -37,14 +37,11 @@ overlap-parity:  ## overlapped serving invisible in the bytes: prefetch on/off p
 value-parity:    ## value-codec gate: vq="f16" byte-identical to legacy packs, 3-mode top-k parity at every engine×codec×vq, quantized top-k overlap floors vs the f16 oracle
 	$(PY) tools/value_parity.py
 
-perf-gate:       ## NaN-fail when a freshly measured pallas_compiled row is slower than the committed jnp row for the same codec, u8_sq rescoring stops beating f16 on HBM bytes, or prefetch-on p95 regresses past prefetch-off
-	$(PY) tools/perf_gate.py
-
 pipeline-smoke:  ## micro-batching scheduler smoke: synthetic trace through the pipeline, every response byte-identical to direct search, ServeStats report
 	$(PY) -m repro.launch.serve --pipeline --engine flat --codec streamvbyte --n-docs 300 --n-queries 16 --requests 96 --deadline-us 500
 	$(PY) -m repro.launch.serve --pipeline --engine seismic --codec dotvbyte --backend pallas --n-docs 400 --n-queries 8 --requests 48 --n-probe 16
 
-check: docs-check serve-roundtrip kernel-parity shard-parity mutation-parity overlap-parity value-parity perf-gate pipeline-smoke ## tier-1 suite + tiny Table-1..7+kernel benchmark pass + docs audit + artifact + parity + mutation + overlap + value + perf + pipeline gates
+check: docs-check serve-roundtrip kernel-parity shard-parity mutation-parity overlap-parity value-parity pipeline-smoke ## tier-1 suite + tiny Table-1..7+kernel benchmark pass + docs audit + artifact + parity + mutation + overlap + value + pipeline gates
 	$(PY) -m benchmarks.run --quick
 
 bench:           ## full benchmark sweep (slow)

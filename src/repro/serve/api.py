@@ -417,7 +417,7 @@ class Retriever:
 
         ``k`` defaults to ``cfg.k`` (the static top-k the search graph
         was traced with); any smaller k is a free slice."""
-        ids, scores = self.plans.search(jnp.asarray(Q))
+        ids, scores = self.plans.search(Q)
         if k is None or k == self.cfg.k:
             return ids, scores
         if k > self.cfg.k:

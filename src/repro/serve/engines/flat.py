@@ -54,14 +54,18 @@ class FlatEngine(EngineImpl):
         resident query batch — ``score_candidate_rows_batch``, the
         kernel registry's ``rows_scores_batch`` under
         ``backend="pallas"``. Per-query results are bitwise those of
-        ``vmap(search_one)`` (the parity suite)."""
+        ``vmap(search_one)`` (the parity suite). Named scopes
+        ``flat.score`` and ``flat.topk`` label its device operations in
+        profiler traces."""
         docs = jnp.arange(arrays["nnz_rows"].shape[0], dtype=jnp.int32)
-        scores = score_candidate_rows_batch(
-            cfg.codec, arrays, docs, Q, value_scale, backend=cfg.backend
-        )
-        scores = jnp.where(docs[None, :] < n_docs, scores, -jnp.inf)
-        top_s, idx = jax.lax.top_k(scores, cfg.k)
-        return jnp.take(docs, idx), top_s
+        with jax.named_scope("flat.score"):
+            scores = score_candidate_rows_batch(
+                cfg.codec, arrays, docs, Q, value_scale, backend=cfg.backend
+            )
+            scores = jnp.where(docs[None, :] < n_docs, scores, -jnp.inf)
+        with jax.named_scope("flat.topk"):
+            top_s, idx = jax.lax.top_k(scores, cfg.k)
+            return jnp.take(docs, idx), top_s
 
     def array_specs(
         self,

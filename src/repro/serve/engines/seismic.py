@@ -126,42 +126,48 @@ class SeismicEngine(EngineImpl):
         """One dense query → (ids [k], scores [k]). Pure and static-shape.
 
         arrays: cbs/cbl [dim], sum_comps/sum_vals [n_blocks, s_max],
-        block_docs [n_blocks, bs_max], plus the packed row form."""
+        block_docs [n_blocks, bs_max], plus the packed row form. Each
+        step runs under a named scope (``seismic.select``, ``.phase1``,
+        ``.dedupe``, ``.phase2``, ``.topk``) that labels its device
+        operations in profiler traces."""
         p = self.params(cfg)
         cut, block_budget, n_probe = p["cut"], p["block_budget"], p["n_probe"]
-        # top-cut query components
-        qv, qc = jax.lax.top_k(jnp.abs(q), cut)
-        live = qv > 0
-        # candidate blocks: fixed budget round-robin over the cut comps
-        starts = arrays["cbs"][qc]  # [cut]
-        lens = jnp.where(live, arrays["cbl"][qc], 0)
-        per = block_budget // cut
-        offs = jnp.arange(per)[None, :]  # [1, per]
-        cand = starts[:, None] + offs  # [cut, per]
-        valid = offs < lens[:, None]
-        cand = jnp.where(valid, cand, -1).reshape(-1)  # [budget]
+        with jax.named_scope("seismic.select"):
+            # top-cut query components
+            qv, qc = jax.lax.top_k(jnp.abs(q), cut)
+            live = qv > 0
+            # candidate blocks: fixed budget round-robin over the cut comps
+            starts = arrays["cbs"][qc]  # [cut]
+            lens = jnp.where(live, arrays["cbl"][qc], 0)
+            per = block_budget // cut
+            offs = jnp.arange(per)[None, :]  # [1, per]
+            cand = starts[:, None] + offs  # [cut, per]
+            valid = offs < lens[:, None]
+            cand = jnp.where(valid, cand, -1).reshape(-1)  # [budget]
 
-        # phase 1: summary upper bounds
-        sc = jnp.take(arrays["sum_comps"], jnp.maximum(cand, 0), axis=0)
-        sv = jnp.take(arrays["sum_vals"], jnp.maximum(cand, 0), axis=0)
-        est = (jnp.take(q, sc, axis=0) * sv).sum(-1)
-        est = jnp.where(cand >= 0, est, -jnp.inf)
-        _, probe = jax.lax.top_k(est, n_probe)
-        probe_blocks = jnp.take(cand, probe)
+        with jax.named_scope("seismic.phase1"):  # summary upper bounds
+            sc = jnp.take(arrays["sum_comps"], jnp.maximum(cand, 0), axis=0)
+            sv = jnp.take(arrays["sum_vals"], jnp.maximum(cand, 0), axis=0)
+            est = (jnp.take(q, sc, axis=0) * sv).sum(-1)
+            est = jnp.where(cand >= 0, est, -jnp.inf)
+            _, probe = jax.lax.top_k(est, n_probe)
+            probe_blocks = jnp.take(cand, probe)
 
-        # phase 2: gather candidate docs, dedupe, exact re-score
-        docs = jnp.take(arrays["block_docs"], jnp.maximum(probe_blocks, 0), axis=0)
-        docs = jnp.where((probe_blocks >= 0)[:, None], docs, n_docs).reshape(-1)
-        docs = jnp.sort(docs)
-        dup = jnp.concatenate([jnp.zeros(1, bool), docs[1:] == docs[:-1]])
-        docs = jnp.where(dup, n_docs, docs)
+        with jax.named_scope("seismic.dedupe"):  # gather candidate docs
+            docs = jnp.take(arrays["block_docs"], jnp.maximum(probe_blocks, 0), axis=0)
+            docs = jnp.where((probe_blocks >= 0)[:, None], docs, n_docs).reshape(-1)
+            docs = jnp.sort(docs)
+            dup = jnp.concatenate([jnp.zeros(1, bool), docs[1:] == docs[:-1]])
+            docs = jnp.where(dup, n_docs, docs)
 
-        scores = score_candidate_rows(
-            cfg.codec, arrays, docs, q, value_scale, backend=cfg.backend
-        )
-        scores = jnp.where(docs < n_docs, scores, -jnp.inf)
-        top_s, idx = jax.lax.top_k(scores, cfg.k)
-        return jnp.take(docs, idx), top_s
+        with jax.named_scope("seismic.phase2"):  # exact re-score
+            scores = score_candidate_rows(
+                cfg.codec, arrays, docs, q, value_scale, backend=cfg.backend
+            )
+            scores = jnp.where(docs < n_docs, scores, -jnp.inf)
+        with jax.named_scope("seismic.topk"):
+            top_s, idx = jax.lax.top_k(scores, cfg.k)
+            return jnp.take(docs, idx), top_s
 
     def array_specs(
         self,

@@ -37,10 +37,11 @@ meets *traffic* instead of one frozen batch. Four layers, stacked:
   so a mutation can never replay a pre-mutation top-k.
 
 * ``ServeStats`` — the metrics contract: QPS, p50/p95/p99 end-to-end
-  latency, result-cache hit rate, per-bucket dispatch counts and
-  occupancy (real queries / bucket capacity), the plan-cache recompile
-  count, and the result-cache invalidation counters (flushes and
-  entries dropped).
+  latency, queue wait, result-cache hit rate, per-bucket dispatch
+  counts and occupancy (real queries / bucket capacity), the plan-cache
+  recompile count and uploaded query bytes, and the result-cache
+  invalidation counters (flushes and entries dropped). With
+  ``obs.enable()``, each layer also records a profiler span.
 
 Determinism contract (tests/test_pipeline.py, ``make pipeline-smoke``):
 bucketed/padded/cached serving returns byte-identical top-k ids and
@@ -65,6 +66,7 @@ just benchmarked.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from collections import OrderedDict, deque
@@ -73,6 +75,8 @@ from typing import TYPE_CHECKING, Callable, Dict, List, Optional, Sequence, Tupl
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from . import obs
 
 if TYPE_CHECKING:  # import cycle: api.py imports this module at runtime
     from .api import Retriever
@@ -193,16 +197,23 @@ class SearchPlan:
     path. Calls whose padded batch matches the warmed shape/dtype run
     the AOT executable directly; anything else falls back to ordinary
     jit dispatch (which shares XLA's compilation cache, so nothing
-    compiles twice)."""
+    compiles twice).
 
-    __slots__ = ("key", "_fn", "_compiled", "_warm_sig", "_lock")
+    ``owner`` is the plan cache or facade that hands the plan out: each
+    call handed a host batch adds the batch's bytes, before padding, to
+    the owner's ``upload_bytes`` under the owner's ``_lock`` (a device
+    array, as a facade hands its sub-plans, uploads nothing)."""
 
-    def __init__(self, key: PlanKey, fn: Callable):
+    __slots__ = ("key", "_fn", "_compiled", "_warm_sig", "_lock", "_owner", "_called")
+
+    def __init__(self, key: PlanKey, fn: Callable, owner):
         self.key = key
         self._fn = fn
         self._compiled: Optional[Callable] = None
         self._warm_sig: Optional[Tuple[int, int, np.dtype]] = None
         self._lock = threading.Lock()
+        self._owner = owner
+        self._called = False
 
     def warm(self, dim: int, dtype=jnp.float32) -> bool:
         """AOT-compile this plan for ``[bucket, dim]`` batches of
@@ -227,20 +238,28 @@ class SearchPlan:
         return self._compiled
 
     def __call__(self, Q) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        Q = jnp.asarray(Q)
-        n, bucket = Q.shape[0], self.key.bucket
-        if n > bucket:
-            raise ValueError(f"batch of {n} exceeds plan bucket {bucket}")
-        if n < bucket:
-            Q = jnp.concatenate(
-                [Q, jnp.zeros((bucket - n, Q.shape[1]), Q.dtype)]
-            )
-        fn = self._fn
-        if (self._compiled is not None
-                and (bucket, Q.shape[1], np.dtype(Q.dtype)) == self._warm_sig):
-            fn = self._compiled
-        ids, scores = fn(Q)
-        return ids[:n], scores[:n]
+        bucket = self.key.bucket
+        first = not self._called and self._compiled is None
+        self._called = True
+        with obs.span("plan.dispatch", bucket=bucket, first=int(first)):
+            host = not isinstance(Q, jax.Array)
+            Q = jnp.asarray(Q)
+            n = Q.shape[0]
+            if n > bucket:
+                raise ValueError(f"batch of {n} exceeds plan bucket {bucket}")
+            if host:
+                with self._owner._lock:  # plans are called from many threads
+                    self._owner.upload_bytes += Q.nbytes
+            if n < bucket:
+                Q = jnp.concatenate(
+                    [Q, jnp.zeros((bucket - n, Q.shape[1]), Q.dtype)]
+                )
+            fn = self._fn
+            if (self._compiled is not None
+                    and (bucket, Q.shape[1], np.dtype(Q.dtype)) == self._warm_sig):
+                fn = self._compiled
+            ids, scores = fn(Q)
+            return ids[:n], scores[:n]
 
 
 class PlanCache:
@@ -251,11 +270,15 @@ class PlanCache:
     ``SearchPlan``s per bucket; jax's executable cache is keyed by the
     padded shape, so plan keys and compiled programs are 1:1.
     ``compiles`` counts plan creations — the serving-metrics recompile
-    counter. Batches beyond the largest bucket round up to the next
-    power of two, which joins the bucket set (counted as a compile)."""
+    counter; ``upload_bytes`` counts the bytes of the host query
+    batches its plans upload (before padding). Batches beyond
+    the largest bucket round up to the next power of two, which joins
+    the bucket set (counted as a compile).
+
+    The jit'd function is named ``search_<engine>``, so profiler
+    traces name its executables ``jit_search_<engine>``."""
 
     def __init__(self, retriever: "Retriever", buckets: Optional[Sequence[int]] = None):
-        import jax
         from functools import partial
 
         from repro.kernels.modes import backend_mode, resolve_mode
@@ -268,17 +291,17 @@ class PlanCache:
             PlanKey, cfg.engine, cfg.codec, cfg.backend, mode, cfg.k,
             shard=getattr(retriever, "shard", ""), vq=cfg.vq,
         )
-        self._dispatch = jax.jit(
-            partial(
-                retriever.impl.search_batch,
-                cfg,
-                retriever.n_docs,
-                retriever.value_scale,
-                retriever.arrays,
-            )
-        )
+        impl, n_docs = retriever.impl, retriever.n_docs
+        value_scale, arrays = retriever.value_scale, retriever.arrays
+
+        def search(Q):
+            return impl.search_batch(cfg, n_docs, value_scale, arrays, Q)
+
+        search.__name__ = search.__qualname__ = f"search_{cfg.engine}"
+        self._dispatch = jax.jit(search)
         self._plans: Dict[int, SearchPlan] = {}
         self.compiles = 0
+        self.upload_bytes = 0
         self._lock = threading.Lock()
 
     def bucket_for(self, n: int) -> int:
@@ -300,19 +323,20 @@ class PlanCache:
         with self._lock:
             plan = self._plans.get(bucket)
             if plan is None:
-                plan = SearchPlan(self._key(bucket=bucket), self._dispatch)
+                plan = SearchPlan(self._key(bucket=bucket), self._dispatch, self)
                 self._plans[bucket] = plan
                 self.compiles += 1
             return plan
 
     def search(self, Q) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """Pad ``Q`` to its covering bucket and run the warm plan.
-        An empty batch short-circuits to empty ``(0, k)`` results."""
-        Q = jnp.asarray(Q)
-        if Q.shape[0] == 0:
+        """Pad ``Q`` to its covering bucket and run the warm plan, which
+        uploads it. An empty batch short-circuits to empty ``(0, k)``
+        results."""
+        n = np.shape(Q)[0]
+        if n == 0:
             return (jnp.zeros((0, self.k), jnp.int32),
                     jnp.zeros((0, self.k), jnp.float32))
-        return self.get(self.bucket_for(Q.shape[0]))(Q)
+        return self.get(self.bucket_for(n))(Q)
 
 
 # ---------------------------------------------------------------------------
@@ -437,9 +461,12 @@ class ServeStats:
     de-multiplexed), in µs under the pipeline's clock, kept in a
     bounded sliding window (``window`` most recent — a long-lived
     pipeline must not grow without bound, and recent percentiles are
-    the ones that matter operationally). ``snapshot()`` returns one
-    flat dict: qps, p50/p95/p99_us, cache_hit_rate, n_queries,
-    dispatches + occupancy per bucket, recompiles, and the overlap
+    the ones that matter operationally). Queue-wait samples (submit →
+    the start of the query's dispatch, one per dispatched ticket) are
+    kept the same way. ``snapshot()`` returns one flat dict: qps,
+    p50/p95/p99_us, queue_p50/p95_us, cache_hit_rate, n_queries,
+    dispatches + occupancy per bucket, recompiles, upload_bytes (the
+    plan cache's uploaded query bytes), and the overlap
     counters (``prefetch_hits/prefetch_misses`` from the sharded
     prefetcher, ``merge_wall_us/blocked_swap_us`` from background
     compaction — DESIGN.md §11). Recording is lock-guarded so serving
@@ -450,6 +477,7 @@ class ServeStats:
         self.t_start = clock()
         self.n_queries = 0  # completed (cache hits included)
         self.latencies_us = deque(maxlen=window)
+        self.queue_wait_us = deque(maxlen=window)
         self.dispatches: Dict[int, int] = {}  # bucket → dispatch count
         self.occupancy: Dict[int, int] = {}  # bucket → Σ real queries
         # overlap counters (DESIGN.md §11) — synced off the serving
@@ -476,12 +504,19 @@ class ServeStats:
             self.n_queries += 1
             self.latencies_us.append(latency_us)
 
-    def percentile(self, p: float) -> float:
+    def record_queue_wait(self, waits_us) -> None:
         with self._lock:
-            if not self.latencies_us:
+            self.queue_wait_us.extend(waits_us)
+
+    def percentile(self, p: float, samples: Optional[deque] = None) -> float:
+        """``p``-th percentile of the latency samples (or of ``samples``,
+        another of this block's windows)."""
+        with self._lock:
+            got = self.latencies_us if samples is None else samples
+            if not got:
                 return float("nan")
-            samples = np.asarray(list(self.latencies_us))
-        return float(np.percentile(samples, p))
+            got = np.asarray(list(got))
+        return float(np.percentile(got, p))
 
     def sync_overlap(self, retriever) -> None:
         """Pull the overlap counters off the serving stack: prefetch
@@ -523,6 +558,8 @@ class ServeStats:
             "p50_us": self.percentile(50),
             "p95_us": self.percentile(95),
             "p99_us": self.percentile(99),
+            "queue_p50_us": self.percentile(50, self.queue_wait_us),
+            "queue_p95_us": self.percentile(95, self.queue_wait_us),
             "cache_hit_rate": cache.hit_rate if cache is not None else 0.0,
             "cache_invalidations": (
                 cache.invalidations if cache is not None else 0
@@ -533,6 +570,7 @@ class ServeStats:
             "dispatches": dispatches,
             "bucket_occupancy": occ,
             "recompiles": plans.compiles if plans is not None else 0,
+            "upload_bytes": plans.upload_bytes if plans is not None else 0,
             **overlap,
         }
 
@@ -569,12 +607,13 @@ class PendingQuery:
     owning pipeline if the query is still queued (closed-loop callers
     never deadlock on an under-filled bucket)."""
 
-    __slots__ = ("q", "key", "t_submit", "done", "ids", "scores", "from_cache",
+    __slots__ = ("q", "key", "t_submit", "rid", "done", "ids", "scores", "from_cache",
                  "_pipeline")
 
     def __init__(self, pipeline: "Pipeline", q: np.ndarray, key: bytes,
-                 t_submit: float):
+                 t_submit: float, rid: int):
         self._pipeline = pipeline
+        self.rid = rid  # the pipeline's serial of this request
         self.q = q
         self.key = key
         self.t_submit = t_submit
@@ -655,6 +694,10 @@ class Pipeline:
         self._clock = clock
         self.stats = ServeStats(clock)
         self._queue: List[PendingQuery] = []
+        # serials of requests and dispatches: the args that tie one
+        # request's spans together (``obs``)
+        self._rids = itertools.count()
+        self._dids = itertools.count()
         # one scheduler lock across admission + dispatch: submitters
         # from other threads serialize here, so the queue is never torn
         # and at most one dispatch runs at a time (DESIGN.md §11);
@@ -684,26 +727,29 @@ class Pipeline:
         q = np.asarray(q, dtype=np.float32)
         now = self._clock()
         with self._lock:
-            # epoch sync: a mutable retriever bumps ``epoch`` on every
-            # index change (insert/delete/merge commit); any cached
-            # answer predating the bump is stale and must not be served
-            # (DESIGN.md §10) — under the scheduler lock, so a commit
-            # landing mid-admission can't interleave a stale hit
-            ep = getattr(self.retriever, "epoch", None)
-            if ep is not None and ep != self.cache.epoch:
-                self.cache.invalidate(epoch=ep)
-            # key computation is an O(dim) scan — skip it entirely when
-            # the cache is disabled (the strict-exactness path stays lean)
-            caching = self.cache.capacity > 0
-            key = quantized_query_key(q, self.key_dtype) if caching else b""
-            ticket = PendingQuery(self, q, key, now)
-            if caching:
-                hit = self.cache.get(ticket.key)
-                if hit is not None:
-                    ticket.from_cache = True
-                    ticket._complete(hit[0], hit[1], self._clock(), self.stats)
-                    return ticket
-            self._queue.append(ticket)
+            rid = next(self._rids)  # under the lock: rids follow queue order
+            with obs.span("serve.admit", rid=rid):
+                # epoch sync: a mutable retriever bumps ``epoch`` on every
+                # index change (insert/delete/merge commit); any cached
+                # answer predating the bump is stale and must not be
+                # served (DESIGN.md §10) — under the scheduler lock, so a
+                # commit landing mid-admission can't interleave a stale hit
+                ep = getattr(self.retriever, "epoch", None)
+                if ep is not None and ep != self.cache.epoch:
+                    self.cache.invalidate(epoch=ep)
+                # key computation is an O(dim) scan — skip it entirely
+                # when the cache is disabled (the strict-exactness path
+                # stays lean)
+                caching = self.cache.capacity > 0
+                key = quantized_query_key(q, self.key_dtype) if caching else b""
+                ticket = PendingQuery(self, q, key, now, rid)
+                if caching:
+                    hit = self.cache.get(ticket.key)
+                    if hit is not None:
+                        ticket.from_cache = True
+                        ticket._complete(hit[0], hit[1], self._clock(), self.stats)
+                        return ticket
+                self._queue.append(ticket)
             if len(self._queue) >= self.plans.buckets[-1]:
                 self._dispatch()
             return ticket
@@ -734,20 +780,27 @@ class Pipeline:
         Callers hold ``_lock``."""
         if not self._queue:
             return 0
+        start = self._clock()
         cap = self.plans.buckets[-1]
         batch, self._queue = self._queue[:cap], self._queue[cap:]
         bucket = self.plans.bucket_for(len(batch))
-        Q = np.stack([t.q for t in batch])
-        ids, scores = self.plans.get(bucket)(Q)
-        ids, scores = np.asarray(ids), np.asarray(scores)
-        now = self._clock()
-        self.stats.record_dispatch(bucket, len(batch))
-        caching = self.cache.capacity > 0
-        for i, t in enumerate(batch):
-            t._complete(ids[i], scores[i], now, self.stats)
-            if caching:
-                self.cache.put(t.key, ids[i], scores[i])
-        return len(batch)
+        did = next(self._dids)
+        with obs.span("serve.dispatch", did=did, bucket=bucket, n_real=len(batch),
+                      rid_first=batch[0].rid, rid_last=batch[-1].rid):
+            self.stats.record_queue_wait([1e6 * (start - t.t_submit) for t in batch])
+            Q = np.stack([t.q for t in batch])
+            ids, scores = self.plans.get(bucket)(Q)
+            with obs.span("serve.wait", did=did):
+                ids, scores = np.asarray(ids), np.asarray(scores)
+            now = self._clock()
+            self.stats.record_dispatch(bucket, len(batch))
+            caching = self.cache.capacity > 0
+            with obs.span("serve.demux", did=did):
+                for i, t in enumerate(batch):
+                    t._complete(ids[i], scores[i], now, self.stats)
+                    if caching:
+                        self.cache.put(t.key, ids[i], scores[i])
+            return len(batch)
 
     # -- synchronous convenience surface --------------------------------
     def search_batch(self, Q) -> Tuple[np.ndarray, np.ndarray]:

@@ -243,6 +243,7 @@ class MutablePlanCache:
         self.buckets = serve_pipeline.plan_buckets(cfg.batch_size, buckets)
         self.k = cfg.k
         self._plans: Dict[int, serve_pipeline.SearchPlan] = {}
+        self.upload_bytes = 0  # the facade's plans upload; sub-plans get device batches
         self.retired = 0
         self._lock = threading.Lock()
 
@@ -268,16 +269,11 @@ class MutablePlanCache:
                     resolve_mode(backend_mode(cfg.backend)), cfg.k, bucket,
                     shard="mut", gen=gen, vq=cfg.vq,
                 )
-                plan = serve_pipeline.SearchPlan(key, self.retriever._dispatch)
+                plan = serve_pipeline.SearchPlan(key, self.retriever._dispatch, self)
                 self._plans[bucket] = plan
             return plan
 
-    def search(self, Q):
-        Q = jnp.asarray(Q)
-        if Q.shape[0] == 0:
-            return (jnp.zeros((0, self.k), jnp.int32),
-                    jnp.zeros((0, self.k), jnp.float32))
-        return self.get(self.bucket_for(Q.shape[0]))(Q)
+    search = serve_pipeline.PlanCache.search
 
 
 class MutableRetriever:
@@ -810,7 +806,7 @@ class MutableRetriever:
         byte-identical to the post-mutation oracle under exhaustive
         engine budgets (the mutation-parity gate; oracle position
         ``r`` ↔ stable id ``live_ids()[r]``)."""
-        ids, scores = self.plans.search(jnp.asarray(Q))
+        ids, scores = self.plans.search(Q)
         if k is None or k == self.cfg.k:
             return ids, scores
         if k > self.cfg.k:

@@ -233,7 +233,9 @@ class ShardedPlanCache:
     never collide on an executable. ``compiles`` aggregates the
     per-shard counters plus everything evicted shards had compiled:
     out-of-core re-admission recompiles, and the recompile metric
-    counts it honestly."""
+    counts it honestly. ``upload_bytes`` counts the host batches the
+    facade's own plans upload, once whatever the shard count: the
+    per-shard plans are handed the uploaded batch."""
 
     def __init__(
         self,
@@ -245,6 +247,7 @@ class ShardedPlanCache:
         self.buckets = serve_pipeline.plan_buckets(cfg.batch_size, buckets)
         self.k = cfg.k
         self._plans: Dict[int, serve_pipeline.SearchPlan] = {}
+        self.upload_bytes = 0  # the facade's plans upload; sub-plans get device batches
         self._lock = threading.Lock()
 
     # same covering-bucket policy as the monolithic cache
@@ -271,17 +274,12 @@ class ShardedPlanCache:
                     shard=f"*/{cfg.n_shards}", vq=cfg.vq,
                 )
                 plan = serve_pipeline.SearchPlan(
-                    key, self.retriever._dispatch_shards
+                    key, self.retriever._dispatch_shards, self
                 )
                 self._plans[bucket] = plan
             return plan
 
-    def search(self, Q):
-        Q = jnp.asarray(Q)
-        if Q.shape[0] == 0:
-            return (jnp.zeros((0, self.k), jnp.int32),
-                    jnp.zeros((0, self.k), jnp.float32))
-        return self.get(self.bucket_for(Q.shape[0]))(Q)
+    search = serve_pipeline.PlanCache.search
 
 
 class ShardedRetriever:
@@ -713,7 +711,7 @@ class ShardedRetriever:
         """[nq, dim] queries → global (ids [nq, k], scores [nq, k]),
         byte-identical to the unsharded oracle's top-k under exhaustive
         engine budgets (the shard-parity gate)."""
-        ids, scores = self.plans.search(jnp.asarray(Q))
+        ids, scores = self.plans.search(Q)
         if k is None or k == self.cfg.k:
             return ids, scores
         if k > self.cfg.k:

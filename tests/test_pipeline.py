@@ -20,6 +20,7 @@ from repro.serve.pipeline import (
     Pipeline,
     PlanCache,
     ResultCache,
+    ServeStats,
     plan_buckets,
     quantized_query_key,
 )
@@ -168,6 +169,32 @@ def test_full_bucket_dispatches_immediately(collection, queries, host_indexes):
     tickets = [pipe.submit(q) for q in queries[:4]]
     assert all(t.done for t in tickets)  # queue hit the largest bucket
     assert pipe.snapshot()["dispatches"] == {4: 1}
+
+
+def test_queue_wait_samples_under_a_fake_clock(collection, queries, host_indexes):
+    """One sample per dispatched ticket: dispatch start − submit; cache
+    hits are never queued, so they add none."""
+    clock = FakeClock()
+    r = _retriever(collection, host_indexes, "flat", "dotvbyte")
+    pipe = Pipeline(r, buckets=(1, 4), deadline_us=1000.0, cache_size=8, clock=clock)
+    pipe.submit(queries[0])
+    clock.advance_us(300.0)
+    pipe.submit(queries[1])
+    clock.advance_us(800.0)
+    assert pipe.poll() == 2  # the oldest waited past the deadline
+    assert list(pipe.stats.queue_wait_us) == pytest.approx([1100.0, 800.0])
+    t = pipe.submit(queries[0])  # a hit: never queued
+    assert t.done and len(pipe.stats.queue_wait_us) == 2
+    snap = pipe.snapshot()
+    assert snap["queue_p50_us"] == pytest.approx(950.0)
+    assert snap["queue_p95_us"] == pytest.approx(1085.0)
+
+
+def test_queue_wait_window_is_bounded():
+    stats = ServeStats(clock=lambda: 0.0, window=3)
+    stats.record_queue_wait([1.0, 2.0, 3.0, 4.0])
+    assert list(stats.queue_wait_us) == [2.0, 3.0, 4.0]
+    assert np.isnan(ServeStats(clock=lambda: 0.0).snapshot()["queue_p50_us"])
 
 
 def test_ticket_result_flushes(collection, queries, host_indexes):
