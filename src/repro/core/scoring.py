@@ -35,6 +35,7 @@ __all__ = [
     "decode_doc_rows",
     "score_candidate_rows",
     "score_candidate_rows_batch",
+    "score_all_rows_batch",
 ]
 
 
@@ -567,6 +568,34 @@ def score_candidate_rows_batch(
     # query axis on the [C, L] gather would sit in the minor dimension
     # of a TPU layout, padded to 128 lanes (26 GB at 200k rows)
     return jax.lax.map(lambda q: score_doc_rows(q, comps, vals, nnz, scale), Q)
+
+
+def score_all_rows_batch(
+    codec: str,
+    arrays,
+    Q: jnp.ndarray,
+    scale: float,
+    backend: str = "jnp",
+) -> jnp.ndarray:
+    """Score EVERY row of the packed row form (the N+1 rows, sentinel
+    included) against a query batch → [nq, N+1].
+
+    The full-scan form of ``score_candidate_rows_batch`` (``flat``):
+    a kernel backend runs the codec's ``rows_scan_batch`` registry
+    entry, the range form of the rows kernel, which reads the rows as
+    contiguous blocks and needs no candidate ids; the jnp chain is
+    ``score_candidate_rows_batch`` over every row id."""
+    _check_rows_backend(backend)
+    if backend != "jnp":
+        from repro.kernels.modes import backend_mode
+        from repro.kernels.registry import rows_scan_scorer
+
+        fn = rows_scan_scorer(codec)
+        if fn is None:
+            raise _no_rows_kernel(codec, backend)
+        return fn(arrays, Q, scale, backend_mode(backend))
+    docs = jnp.arange(arrays["nnz_rows"].shape[0], dtype=jnp.int32)
+    return score_candidate_rows_batch(codec, arrays, docs, Q, scale, backend)
 
 
 def score_doc_rows(

@@ -17,7 +17,11 @@ them fused. Each entry is a ``KernelSet``:
   ``RetrieverConfig(backend="pallas")`` routes a Retriever through the
   fused path;
 * ``rows_scores_batch`` — same, for a query batch sharing one
-  candidate set (``(arrays, docs, Q, scale) → [nq, C]``).
+  candidate set (``(arrays, docs, Q, scale) → [nq, C]``);
+* ``rows_scan_batch`` — every row of the table against a query batch
+  (``(arrays, Q, scale) → [nq, N+1]``): the range form of the rows
+  kernel, which reads contiguous row blocks and needs no candidate ids
+  (``flat``'s full scan; ``scoring.score_all_rows_batch``).
 
 Registering a ``KernelSet`` under a layout codec's name makes EVERY
 engine serve that codec fused with zero engine edits — the exact
@@ -60,6 +64,7 @@ __all__ = [
     "available_kernels",
     "rows_scorer",
     "rows_batch_scorer",
+    "rows_scan_scorer",
 ]
 
 
@@ -76,6 +81,8 @@ class KernelSet:
     rows_scores: Optional[Callable] = None
     #: (arrays, docs [C], Q [nq, dim], scale, mode=None) → [nq, C]
     rows_scores_batch: Optional[Callable] = None
+    #: (arrays, Q [nq, dim], scale, mode=None) → [nq, N+1], every row
+    rows_scan_batch: Optional[Callable] = None
 
 
 _KERNELS: Dict[str, Callable[[], KernelSet]] = {}
@@ -122,6 +129,16 @@ def rows_batch_scorer(codec: str) -> Optional[Callable]:
     if factory is None:
         return None
     return factory().rows_scores_batch
+
+
+def rows_scan_scorer(codec: str) -> Optional[Callable]:
+    """The fused every-row entry for ``codec`` (the range form), or
+    None when unregistered (``scoring.score_all_rows_batch`` then
+    raises)."""
+    factory = _KERNELS.get(codec)
+    if factory is None:
+        return None
+    return factory().rows_scan_batch
 
 
 # ---------------------------------------------------------------------------
@@ -204,6 +221,28 @@ def _make_rows_batch(codec: str):
     return rows_batch
 
 
+def _make_rows_scan(codec: str):
+    rows_batch = _make_rows_batch(codec)
+
+    def rows_scan(arrays, Q, scale, mode=None):
+        from repro.core import values as value_codecs
+
+        low = resolve_lowering(mode)
+        if low in ("jnp", "xla"):  # the gather chains over every row
+            docs = jnp.arange(arrays["nnz_rows"].shape[0], dtype=jnp.int32)
+            return rows_batch(arrays, docs, Q, scale, mode)
+        return rows_dot.rows_scan_batch(
+            codec,
+            pad_query_lanes(jnp.asarray(Q, jnp.float32)),
+            _rows_arrays(arrays),
+            scale=float(scale),
+            vq=value_codecs.infer_rows_vq(arrays),
+            interpret=low == "interpret",
+        )
+
+    return rows_scan
+
+
 @register_kernels("dotvbyte")
 def _dotvbyte_kernels() -> KernelSet:
     return KernelSet(
@@ -212,6 +251,7 @@ def _dotvbyte_kernels() -> KernelSet:
         block_scores_batch=score_dotvbyte_batch,
         rows_scores=_make_rows("dotvbyte"),
         rows_scores_batch=_make_rows_batch("dotvbyte"),
+        rows_scan_batch=_make_rows_scan("dotvbyte"),
     )
 
 
@@ -223,6 +263,7 @@ def _streamvbyte_kernels() -> KernelSet:
         block_scores_batch=score_streamvbyte_batch,
         rows_scores=_make_rows("streamvbyte"),
         rows_scores_batch=_make_rows_batch("streamvbyte"),
+        rows_scan_batch=_make_rows_scan("streamvbyte"),
     )
 
 
@@ -234,6 +275,7 @@ def _bitpack_kernels() -> KernelSet:
         block_scores_batch=score_bitpack_batch,
         rows_scores=_make_rows("bitpack"),
         rows_scores_batch=_make_rows_batch("bitpack"),
+        rows_scan_batch=_make_rows_scan("bitpack"),
     )
 
 
@@ -246,4 +288,5 @@ def _uncompressed_kernels() -> KernelSet:
         codec="uncompressed",
         rows_scores=_make_rows("uncompressed"),
         rows_scores_batch=_make_rows_batch("uncompressed"),
+        rows_scan_batch=_make_rows_scan("uncompressed"),
     )

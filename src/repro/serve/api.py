@@ -183,8 +183,8 @@ class EngineImpl:
         independent of batch-mates, which is what makes bucket padding
         sound. Engines whose candidate sets are query-independent
         override this with a genuinely batched decode-once/score-many
-        dispatch (``FlatEngine`` → ``scoring.score_candidate_rows_
-        batch`` → the kernel registry's ``rows_scores_batch``)."""
+        dispatch (``FlatEngine`` → ``scoring.score_all_rows_batch`` →
+        the kernel registry's ``rows_scan_batch``)."""
         return jax.vmap(
             partial(self.search_one, cfg, n_docs, value_scale, arrays)
         )(Q)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.core import layout
 from repro.core.forward_index import ForwardIndex
-from repro.core.scoring import score_candidate_rows, score_candidate_rows_batch
+from repro.core.scoring import score_all_rows_batch
 
 from ..api import EngineImpl, RetrieverConfig, register_engine, row_array_specs
 
@@ -38,11 +38,12 @@ class FlatEngine(EngineImpl):
 
     # -- serving --------------------------------------------------------
     def search_one(self, cfg: RetrieverConfig, n_docs: int, value_scale: float, arrays, q):
-        """One dense query → (ids [k], scores [k]): score ALL rows."""
+        """One dense query → (ids [k], scores [k]): score ALL rows, by
+        the same every-row path as ``search_batch``."""
         docs = jnp.arange(arrays["nnz_rows"].shape[0], dtype=jnp.int32)
-        scores = score_candidate_rows(
-            cfg.codec, arrays, docs, q, value_scale, backend=cfg.backend
-        )
+        scores = score_all_rows_batch(
+            cfg.codec, arrays, q[None], value_scale, backend=cfg.backend
+        )[0]
         scores = jnp.where(docs < n_docs, scores, -jnp.inf)
         top_s, idx = jax.lax.top_k(scores, cfg.k)
         return jnp.take(docs, idx), top_s
@@ -51,16 +52,17 @@ class FlatEngine(EngineImpl):
         """Genuinely batched full scan (DESIGN.md §8): every query
         shares the one candidate set (all rows), so the pipeline's
         bucketed dispatch decodes each row ONCE and scores the whole
-        resident query batch — ``score_candidate_rows_batch``, the
-        kernel registry's ``rows_scores_batch`` under
-        ``backend="pallas"``. Per-query results are bitwise those of
-        ``vmap(search_one)`` (the parity suite). Named scopes
+        resident query batch — ``score_all_rows_batch``, the range
+        form of the rows kernel (``rows_scan_batch``: contiguous row
+        blocks, no candidate ids) under ``backend="pallas"``. Per-query
+        results are bitwise those of ``vmap(search_one)`` (the parity
+        suite). Named scopes
         ``flat.score`` and ``flat.topk`` label its device operations in
         profiler traces."""
         docs = jnp.arange(arrays["nnz_rows"].shape[0], dtype=jnp.int32)
         with jax.named_scope("flat.score"):
-            scores = score_candidate_rows_batch(
-                cfg.codec, arrays, docs, Q, value_scale, backend=cfg.backend
+            scores = score_all_rows_batch(
+                cfg.codec, arrays, Q, value_scale, backend=cfg.backend
             )
             scores = jnp.where(docs[None, :] < n_docs, scores, -jnp.inf)
         with jax.named_scope("flat.topk"):

@@ -232,6 +232,146 @@ def test_rows_kernel_batch_matches_vmapped_single(codec):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("codec", available_kernels())
+def test_flat_batch_matches_vmapped_single_pallas(codec):
+    """flat's batched full scan and ``vmap(search_one)`` both take the
+    range form under ``backend="pallas"`` (interpret mode here): each
+    query's ids and scores are bitwise equal."""
+    from repro.serve.api import RetrieverConfig, get_engine
+
+    rng = np.random.default_rng(31 + sum(codec.encode()))
+    fwd, Q = _scan_fixture(rng, dim=512, full=128)
+    arrays = _scan_arrays(fwd, codec)
+    cfg = RetrieverConfig(engine="flat", codec=codec, k=5, backend="pallas_interpret")
+    impl, scale = get_engine("flat"), float(fwd.value_format.scale)
+    ids_b, sc_b = impl.search_batch(cfg, fwd.n_docs, scale, arrays, jnp.asarray(Q))
+    ids_v, sc_v = jax.vmap(
+        lambda q: impl.search_one(cfg, fwd.n_docs, scale, arrays, q)
+    )(jnp.asarray(Q))
+    np.testing.assert_array_equal(np.asarray(ids_b), np.asarray(ids_v))
+    np.testing.assert_array_equal(np.asarray(sc_b), np.asarray(sc_v))
+
+
+# ---------------------------------------------------------------------------
+# the range form of the rows kernel (every row, contiguous row blocks)
+# ---------------------------------------------------------------------------
+
+from repro.core.scoring import score_candidate_rows_batch  # noqa: E402
+from repro.core import values as value_codecs  # noqa: E402
+from repro.kernels import rows_dot  # noqa: E402
+
+
+def _scan_fixture(rng, dim=1024, full=256):
+    """A row table whose N+1 rows end in a ragged block of the range
+    form, with empty rows and rows at full capacity (nnz = ``full``),
+    and three queries (below one query group) whose id union spans
+    several 128-id chunks."""
+    docs = []
+    for d in range(rows_dot.R_SCAN + 12):  # N+1 = R_SCAN + 13
+        n = 0 if d % 9 == 4 else (full if d % 9 == 7 else int(rng.integers(1, full)))
+        c = np.sort(rng.choice(dim, size=n, replace=False))
+        docs.append((c, rng.gamma(2.0, 0.5, size=n).astype(np.float32) + 0.05))
+    fwd = ForwardIndex.from_docs(docs, dim, value_format="f16")
+    Q = np.stack([_query(rng, dim, nnz=200) for _ in range(3)])
+    return fwd, Q
+
+
+def _scan_arrays(fwd, codec, vq="f16"):
+    packed = layout.pack_rows(fwd, codec=codec, vq=vq)
+    assert packed.l_max == np.diff(fwd.offsets).max()  # full rows: nnz = l_max
+    assert packed.vals_rows.shape[0] % rows_dot.R_SCAN != 0
+    return {k: jnp.asarray(v) for k, v in packed.arrays().items()}
+
+
+@pytest.mark.parametrize("vq", value_codecs.VALUE_CODECS)
+@pytest.mark.parametrize("codec", available_kernels())
+def test_rows_scan_matches_jnp_chain(codec, vq):
+    """The range form scores every row as the jnp chain does, for every
+    codec × value codec; the sentinel and empty rows score exactly 0."""
+    rng = np.random.default_rng(101 + sum(codec.encode()))
+    fwd, Q = _scan_fixture(rng, full=128 * value_codecs.code_factor(vq))
+    arrays = _scan_arrays(fwd, codec, vq)
+    scale = float(fwd.value_format.scale)
+    docs = jnp.arange(fwd.n_docs + 1, dtype=jnp.int32)
+    want = np.asarray(score_candidate_rows_batch(
+        codec, arrays, docs, jnp.asarray(Q), scale, backend="jnp"
+    ))
+    got = np.asarray(get_kernels(codec).rows_scan_batch(
+        arrays, jnp.asarray(Q), scale, "pallas_interpret"
+    ))
+    assert got.shape == (len(Q), fwd.n_docs + 1)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    empty = np.append(np.flatnonzero(np.diff(fwd.offsets) == 0), fwd.n_docs)
+    np.testing.assert_array_equal(got[:, empty], np.zeros((len(Q), len(empty))))
+
+
+@pytest.mark.parametrize("codec", available_kernels())
+def test_rows_scan_matches_gather_form(codec):
+    """The range form == the gather form over every row id."""
+    rng = np.random.default_rng(7)
+    fwd, Q = _scan_fixture(rng, full=128)
+    arrays = _scan_arrays(fwd, codec)
+    scale = float(fwd.value_format.scale)
+    ks = get_kernels(codec)
+    docs = jnp.arange(fwd.n_docs + 1, dtype=jnp.int32)
+    got = ks.rows_scan_batch(arrays, jnp.asarray(Q), scale, "pallas_interpret")
+    want = ks.rows_scores_batch(arrays, docs, jnp.asarray(Q), scale, "pallas_interpret")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6)
+
+
+def _pallas_calls(jaxpr, out):
+    """Every pallas_call in a jaxpr and its sub-jaxprs → [(name, grid,
+    scalar-prefetch operand count)]."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            gm = e.params["grid_mapping"]
+            out.append((e.params["name"], gm.grid, gm.num_index_operands))
+        for v in e.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else [v]:
+                if isinstance(sub, ClosedJaxpr):
+                    _pallas_calls(sub.jaxpr, out)
+                elif isinstance(sub, Jaxpr):
+                    _pallas_calls(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("engine", ["seismic", "hnsw", "flat"])
+def test_engine_rows_kernel_form(engine):
+    """Arbitrary candidate ids (seismic, hnsw) take the gather form, one
+    candidate row per grid step with the ids scalar-prefetched; flat's
+    full scan takes the range form, one block of R_SCAN rows per step."""
+    from repro.data.synthetic import SyntheticConfig, generate_collection
+    from repro.serve.api import Retriever, RetrieverConfig
+
+    col = generate_collection(SyntheticConfig(
+        name="t", dim=1024, n_docs=300, n_queries=2, doc_nnz_mean=40.0,
+        query_nnz_mean=12.0, seed=0,
+    ), value_format="f16")
+    params = {
+        "seismic": dict(cut=8, block_budget=256, n_probe=48, n_postings=300, block_size=16),
+        "hnsw": dict(beam=48, iters=48, n_seeds=4, m=8, ef_construction=32),
+        "flat": {},
+    }[engine]
+    r = Retriever.build(col.fwd, RetrieverConfig(
+        engine=engine, codec="dotvbyte", backend="pallas_interpret", params=params,
+    ))
+    Q = jnp.asarray(np.stack([col.query_dense(i) for i in range(2)]))
+    search = lambda Q: r.impl.search_batch(r.cfg, r.n_docs, r.value_scale, r.arrays, Q)
+    calls = _pallas_calls(jax.make_jaxpr(search)(Q).jaxpr, [])
+    assert calls
+    for name, grid, n_prefetch in calls:
+        if engine == "flat":
+            assert name == "rows_scan_dotvbyte_f16"
+            assert grid[-1] == -(-(col.fwd.n_docs + 1) // rows_dot.R_SCAN)
+            assert n_prefetch == 1  # the chunk counts alone
+        else:
+            assert name == "rows_dotvbyte_f16"
+            assert grid[-1] % 128 == 0  # one padded candidate per step
+            assert n_prefetch >= 2  # the candidate ids among them
+
+
 def test_kernel_registry_surface():
     """Registry mirrors the layout registry: every layout codec is
     fused, unknown names raise listing the known ones, and a kernel

@@ -135,6 +135,31 @@ def test_rows_kernel_compiles_quantized_values(vq, one_chip):
     _assert_kernel(compiled)
 
 
+@pytest.mark.parametrize("codec", CODECS)
+def test_rows_scan_compiles(codec, one_chip):
+    """The range form ``flat`` serves through: every row of a
+    million-row table in contiguous blocks, a full query group."""
+    compiled = rows_dot.rows_scan_batch.lower(
+        codec,
+        jax.ShapeDtypeStruct((rows_dot.Q_GROUP, VOCAB), jnp.float32, sharding=one_chip),
+        _row_arrays(codec, "f16", one_chip),
+        scale=1.0, vq="f16", interpret=False,
+    ).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("vq", ("u8_sq", "u4_sq", "pq"))
+def test_rows_scan_compiles_quantized_values(vq, one_chip):
+    """The range form's dequant stage, per-block clip ranges included."""
+    compiled = rows_dot.rows_scan_batch.lower(
+        "dotvbyte",
+        jax.ShapeDtypeStruct((rows_dot.Q_GROUP, VOCAB), jnp.float32, sharding=one_chip),
+        _row_arrays("dotvbyte", vq, one_chip),
+        scale=1.0, vq=vq, interpret=False,
+    ).compile()
+    _assert_kernel(compiled)
+
+
 def test_mesh_search_compiles_for_four_chips(topo, monkeypatch):
     """The sharded ``flat`` search over a 4-chip mesh: one shard per
     device, the fused kernel inside, the top-k merged by all-gather."""
