@@ -29,6 +29,25 @@ def test_every_cell_resolves_to_its_files():
             assert callable(spec.metric_reader(m["name"]))
 
 
+def test_seismic_cell_resolves_to_its_metrics():
+    from bench.tests import harness
+
+    bench = harness.benchmark()
+    cell = spec.resolve(bench, "splade-seismic.open")
+    assert cell.traffic["loop"] == "open" and cell.config["retriever"]["engine"] == "seismic"
+    assert {m["name"] for m in cell.end_to_end} == {
+        "p95_ms", "recall_at_10", "setup_s", "index_bytes_per_doc"}
+    assert {m["name"] for m in cell.per_layer} == {
+        "rows_kernel_ms_per_q.open", "engine_ms_per_q.open", "batch_fill.open",
+        "gen_lag_ms.open", "device_idle.open"}
+    for m in cell.per_layer:
+        assert callable(spec.metric_reader(m["name"]))
+    flat = spec.resolve(bench, "splade-flat.batch32")
+    assert {m["name"] for m in flat.end_to_end} == {"setup_s", "qps", "index_bytes_per_doc"}
+    assert {m["name"] for m in flat.per_layer} == {
+        "rows_kernel_roofline.batch", "device_idle.batch"}
+
+
 def test_every_metric_reader_finds_nothing_in_an_empty_reduction():
     for m in spec.load_benchmark()["per_layer"]:
         assert spec.metric_reader(m["name"])({}) is None
